@@ -24,17 +24,18 @@ keeps the measured overlap honest on a single-core CI box, where real
 concurrent *compute* cannot speed anything up.  ``--real`` swaps in the
 real bucketed vmap trainer for multi-core hosts (reported, not gated).
 
-The module forces ``--xla_force_host_platform_device_count=4`` before jax
-initializes so the device-affine scheduler has 4 devices to shard buckets
-across; run it as a subprocess (``python -m benchmarks.pipeline_bench``),
-which is exactly how benchmarks/run.py wires it in.
+Run as a script (``python -m benchmarks.pipeline_bench``) it forces
+``--xla_force_host_platform_device_count=4`` before jax initializes, so on
+the CPU the device-affine scheduler has 4 devices to shard buckets across.
+Imported (benchmarks/run.py calls :func:`run` in its own process) it uses
+whatever devices that process sees.
 """
 from __future__ import annotations
 
 import os
 
 _FORCE = "--xla_force_host_platform_device_count"
-if _FORCE not in os.environ.get("XLA_FLAGS", ""):
+if __name__ == "__main__" and _FORCE not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + f" {_FORCE}=4").strip()
 

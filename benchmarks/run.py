@@ -5,35 +5,8 @@ Prints ``name,us_per_call,derived`` CSV (harness contract).  Full-size runs:
 a few minutes.
 """
 import argparse
-import subprocess
 import sys
 import time
-
-
-def _run_pipeline_bench(args) -> list:
-    """The overlapped-pipeline bench needs 4 forced host devices, and
-    ``--xla_force_host_platform_device_count`` only takes effect before jax
-    initializes — by this point the in-process benches already did.  So it
-    runs as a subprocess (the module stages its own XLA_FLAGS) and its CSV
-    rows are folded back into ours."""
-    cmd = [sys.executable, "-m", "benchmarks.pipeline_bench"]
-    if args.full:
-        cmd.append("--full")
-    if args.json:
-        cmd += ["--json", "BENCH_pipeline.json"]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
-    print(proc.stderr, file=sys.stderr, end="")
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"pipeline_bench failed (rc={proc.returncode}):\n{proc.stdout}")
-    rows = []
-    for line in proc.stdout.splitlines():
-        if not line or line.startswith("#"):
-            continue
-        name, us, derived = line.split(",", 2)
-        rows.append({"name": name, "us_per_call": float(us),
-                     "derived": derived.strip().strip('"')})
-    return rows
 
 
 def main() -> None:
@@ -46,6 +19,8 @@ def main() -> None:
                     help="also write machine-readable per-bench results "
                          "(BENCH_<name>.json) for perf-trajectory tracking")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rows = []
     t0 = time.time()
@@ -55,6 +30,7 @@ def main() -> None:
         kernel_bench,
         multi_platform_bench,
         nas_loop_bench,
+        pipeline_bench,
         population_eval_bench,
         roofline_table,
         router_bench,
@@ -87,7 +63,16 @@ def main() -> None:
     if args.json:
         train_bench.write_json(train_loop_rows, "BENCH_train_loop.json")
         print("# wrote BENCH_train_loop.json", file=sys.stderr)
-    rows += _run_pipeline_bench(args)
+    # in-process: one process holds the chip, so no bench runs as a child.
+    # Here the search shards over the devices this process sees (one on
+    # the CPU unless XLA_FLAGS forces more before the run starts).
+    pipeline_rows, pipeline_summary = pipeline_bench.run(
+        log=lambda *a: print(*a, file=sys.stderr), smoke=not args.full)
+    rows += pipeline_rows
+    if args.json:
+        pipeline_bench.write_json(pipeline_rows, pipeline_summary,
+                                  "BENCH_pipeline.json")
+        print("# wrote BENCH_pipeline.json", file=sys.stderr)
     fault_rows, fault_summary = fault_bench.run(
         log=lambda *a: print(*a, file=sys.stderr), smoke=not args.full)
     rows += fault_rows

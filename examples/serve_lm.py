@@ -10,5 +10,6 @@ import sys
 from repro.launch.serve import main
 
 if __name__ == "__main__":
-    main(sys.argv[1:] if len(sys.argv) > 1 else
-         ["--arch", "qwen3-4b", "--requests", "6", "--max-new", "8"])
+    main(["--reduced"] + (sys.argv[1:] if len(sys.argv) > 1 else
+                          ["--arch", "qwen3-4b", "--requests", "6",
+                           "--max-new", "8"]))

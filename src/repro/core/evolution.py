@@ -258,16 +258,18 @@ class EvolutionarySearch:
             lr=config.lr, seed=config.seed))
         # bucketed vmap-stacked training (DESIGN.md §9): the default unless
         # a scalar train_fn is injected (tests) or the config opts out.
+        # stage_cache holds the datasets the trainer placed on devices,
+        # (input_length, device) -> arrays, for the whole search
+        self.stage_cache: Dict[tuple, tuple] = {}
         if batch_train_fn is not None:
             self._batch_train_fn = batch_train_fn
         elif train_fn is None and config.batch_training:
-            stage_cache: Dict[tuple, tuple] = {}  # device dataset, per search
             self._batch_train_fn = lambda gs, device=None: \
                 train_candidates_batched(
                     gs, data_train, data_val, space=self.space,
                     steps=config.train_steps, batch_size=config.train_batch,
-                    lr=config.lr, seed=config.seed, stage_cache=stage_cache,
-                    device=device)
+                    lr=config.lr, seed=config.seed,
+                    stage_cache=self.stage_cache, device=device)
         else:
             self._batch_train_fn = None
         self._batch_fn_takes_device = self._fn_takes_device(
@@ -296,6 +298,11 @@ class EvolutionarySearch:
         # guards evaluated_hashes: the async pipeline's on_result hook
         # admits results from scheduler worker threads
         self._cache_lock = threading.Lock()
+        # training outcomes over the search's lifetime: "failed" got no
+        # result after its retries, "diverged" was quarantined for
+        # non-finite objectives (both keep the pessimistic row)
+        self.train_outcomes = {"trained": 0, "failed": 0, "diverged": 0}
+        self.quarantined_devices: List[str] = []
 
     @staticmethod
     def _poison_result(value):
@@ -528,6 +535,7 @@ class EvolutionarySearch:
         failure OR divergence) into ``pop`` + the dormant-gene cache, and
         return the per-device busy time of the dispatched jobs."""
         results, raw = self._collect_training(sub)
+        self.quarantined_devices += [str(d) for d in sub.run.quarantined]
         if sub.run.quarantined:
             self.log(f"[nas] WARNING: quarantined device(s) "
                      f"{[str(d) for d in sub.run.quarantined]} after "
@@ -552,10 +560,14 @@ class EvolutionarySearch:
                              f"(non-finite objectives) — quarantined with "
                              f"pessimistic row")
                     exp = self._exp_worst.copy()
+                    self.train_outcomes["diverged"] += 1
+                else:
+                    self.train_outcomes["trained"] += 1
             else:  # failed after retries: pessimistic objectives, stay in
                 self.log(f"[nas] candidate {pop.phash[i]} failed: "
                          f"{r.error.splitlines()[-1] if r.error else '?'}")
                 exp = self._exp_worst.copy()
+                self.train_outcomes["failed"] += 1
             pop.expensive[i] = exp
             with self._cache_lock:
                 state.evaluated_hashes[str(pop.phash[i])] = exp

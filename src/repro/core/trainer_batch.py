@@ -26,7 +26,8 @@ that guarantee it:
   share one bucket and one compiled program.
 
 Singleton buckets fall back to the scalar path (vmap over one candidate
-buys nothing and would double-compile).
+buys nothing and would double-compile); they train on the same staged
+dataset.
 
 Device affinity (DESIGN.md §11): pass ``device=`` and the bucket's staged
 dataset, stacked index/key/bit arrays and eval batches are committed to
@@ -307,21 +308,17 @@ def train_candidates_batched(
         return got
 
     for sig, rows in bucket_by_signature(genomes, space, use_quant).items():
+        x_tr, y_tr, x_va, y_va = stage(sig[1])
         if len(rows) < min_bucket:
             for i in rows:
-                if device is not None:
-                    with jax.default_device(device):
-                        results[i] = train_candidate(
-                            genomes[i], data_train, data_val, space=space,
-                            steps=steps, batch_size=batch_size, lr=lr,
-                            seed=seeds[i], use_quant=use_quant)
-                else:
+                with jax.default_device(device):
+                    # already at the genome's length: prep_inputs and
+                    # jnp.asarray pass the staged arrays through
                     results[i] = train_candidate(
-                        genomes[i], data_train, data_val, space=space,
+                        genomes[i], (x_tr, y_tr), (x_va, y_va), space=space,
                         steps=steps, batch_size=batch_size, lr=lr,
                         seed=seeds[i], use_quant=use_quant)
             continue
-        x_tr, y_tr, x_va, y_va = stage(sig[1])
         bucket_results = _train_bucket(
             [genomes[i] for i in rows], [seeds[i] for i in rows], sig,
             space, x_tr, y_tr, x_va, y_va, steps, batch_size, lr,

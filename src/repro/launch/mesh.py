@@ -6,17 +6,26 @@ dry-run must set XLA_FLAGS before jax initializes.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 from repro.distributed.sharding import Physical, default_rules
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with every axis Auto.  The sharding rules place
+    arrays by ``with_sharding_constraint`` under the mesh, which JAX's
+    default Explicit axes refuse ("can only refer to Auto axes")."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def local_search_devices(max_devices: Optional[int] = None) -> List:

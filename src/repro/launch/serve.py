@@ -14,9 +14,10 @@ admission, bucketed prefill, no wave barrier — DESIGN.md §12):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --reduced \
       --requests 12 --max-new 16 --engine
 
-and the resilient deployment is the engine behind
-:class:`repro.serve.ReplicaRouter` (replicated dispatch with health
-checks, failover, load shedding and hedging — DESIGN.md §14):
+Without ``--reduced`` the arch runs at its published widths.  The
+resilient deployment is the engine behind :class:`repro.serve.ReplicaRouter`
+(replicated dispatch with health checks, failover, load shedding and
+hedging — DESIGN.md §14):
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --reduced \
       --requests 12 --max-new 16 --router --replicas 2
@@ -37,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ALL_ARCHS, get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.serve.engine import EngineConfig, ServeEngine, ServeRequest
 
@@ -134,10 +136,13 @@ class BatchedServer:
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b", choices=ALL_ARCHS)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="reduced config (CPU-runnable)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
+    ap.add_argument("--cache-len", type=int, default=64,
+                    help="per-slot KV-cache capacity in tokens")
     ap.add_argument("--engine", action="store_true",
                     help="use the continuous-batching ServeEngine instead "
                          "of the wave-barrier baseline")
@@ -160,6 +165,7 @@ def main(argv=None) -> None:
     if args.paged and not (args.engine or args.router):
         ap.error("--paged needs --engine or --router (the wave-barrier "
                  "baseline is dense-only)")
+    enable_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     bundle = build_model(cfg)
@@ -171,7 +177,7 @@ def main(argv=None) -> None:
                          max_new=args.max_new)
             for i in range(args.requests)]
     t0 = time.time()
-    ecfg = EngineConfig(slots=args.slots, cache_len=64,
+    ecfg = EngineConfig(slots=args.slots, cache_len=args.cache_len,
                         pad_to=8 if bundle.prefill_pads else 1,
                         paged=args.paged, block_size=args.block_size,
                         n_blocks=args.blocks)
@@ -189,7 +195,7 @@ def main(argv=None) -> None:
         print(f"engine stats: {engine.stats()}")
     else:
         server = BatchedServer(bundle, params, slots=args.slots,
-                               cache_len=64)
+                               cache_len=args.cache_len)
         done = server.run(reqs)
     dt = time.time() - t0
     total_tokens = sum(len(r.out) for r in done)

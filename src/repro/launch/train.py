@@ -1,9 +1,9 @@
 """Production training driver.
 
-On a real TPU pod this builds the production mesh, installs sharding rules,
-and runs the fault-tolerant loop with sharded inputs.  On the CPU box it
-falls back to a single-device mesh with a reduced config (``--reduced``),
-exercising the identical code path end to end.
+On a 256-device pod this builds the production mesh, installs sharding
+rules, and runs the fault-tolerant loop with sharded inputs.  On fewer
+devices it builds a ``(devices, 1)`` data-parallel mesh; ``--reduced``
+swaps in a narrow config that runs on the CPU through the same code path.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --reduced \
       --steps 50 --batch 8 --seq 128
@@ -11,6 +11,8 @@ exercising the identical code path end to end.
 from __future__ import annotations
 
 import argparse
+import os
+import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +20,8 @@ import jax.numpy as jnp
 from repro.configs import ALL_ARCHS, get_config, reduced_config
 from repro.data.lm import LMDataConfig, data_iterator
 from repro.distributed.sharding import axis_rules
-from repro.launch.mesh import make_production_mesh, rules_for
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh, make_production_mesh, rules_for
 from repro.models.registry import build_model
 from repro.training.loop import LoopConfig, train_loop
 
@@ -29,12 +32,14 @@ def main(argv=None) -> None:
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(), "repro_train"))
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--reduced", action="store_true",
                     help="reduced config (CPU-runnable)")
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     bundle = build_model(cfg)
@@ -45,7 +50,7 @@ def main(argv=None) -> None:
         rules = rules_for(args.arch, multi_pod=args.multi_pod,
                           global_batch=args.batch)
     else:
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+        mesh = make_mesh((n_dev, 1), ("data", "model"))
         rules = rules_for(args.arch, multi_pod=False,
                           global_batch=args.batch)
 

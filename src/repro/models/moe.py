@@ -146,14 +146,9 @@ def moe_block(p: Dict[str, jnp.ndarray], x: jnp.ndarray, cfg: ModelConfig,
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map without replication checking, across jax versions
-    (jax.shard_map/check_vma is the new API; experimental/check_rep the old)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """shard_map without replication checking."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _dispatch_local(ids, n_buckets, capacity):

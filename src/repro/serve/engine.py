@@ -161,14 +161,23 @@ class ServeEngine:
                                and "blocks" not in v}
             self._tables_dirty = False
 
+        def _greedy(logits):
+            # greedy next token per row, and whether the row's logits were
+            # all finite (the nonfinite_rows health counter); the logits
+            # themselves never leave the device
+            return jnp.argmax(logits, axis=-1), \
+                jnp.isfinite(logits).all(axis=-1)
+
         def _prefill(params, tokens, lens):
-            return bundle.prefill_slotted(
+            logits, cache1 = bundle.prefill_slotted(
                 params, {"tokens": tokens, "lens": lens,
                          "cache_len": cfg.cache_len})
+            return _greedy(logits), cache1
 
         def _decode(params, cache, tokens, active):
-            return bundle.decode_slotted(
+            logits, cache = bundle.decode_slotted(
                 params, cache, {"tokens": tokens, "active": active})
+            return _greedy(logits), cache
 
         def _splice(cache, cache1, slot_idx):
             # scatter each prefill row's cache into its slot; rows whose
@@ -183,12 +192,14 @@ class ServeEngine:
             return out
 
         def _prefill_paged(params, tokens, lens):
-            return bundle.prefill_paged(
+            logits, rows = bundle.prefill_paged(
                 params, {"tokens": tokens, "lens": lens})
+            return _greedy(logits), rows
 
         def _decode_paged(params, cache, tokens, active):
-            return bundle.decode_paged(
+            logits, cache = bundle.decode_paged(
                 params, cache, {"tokens": tokens, "active": active})
+            return _greedy(logits), cache
 
         def _splice_paged(cache, rows, slot_idx, blk, off):
             # scatter prefill rows into the block pool: (B, L) block /
@@ -237,6 +248,7 @@ class ServeEngine:
         self.decode_steps = 0
         self.prefill_calls = 0
         self.shed_blocks = 0        # paged OOM sheds (explicit, counted)
+        self.nonfinite_rows = 0     # live rows whose logits held NaN/inf
         self.peak_concurrency = 0   # max sequences simultaneously in flight
 
     def submit(self, req: ServeRequest) -> bool:
@@ -319,6 +331,7 @@ class ServeEngine:
             "prefill_calls": self.prefill_calls,
             "peak_concurrency": self.peak_concurrency,
             "shed_blocks": self.shed_blocks,
+            "nonfinite_rows": self.nonfinite_rows,
         }
         if self.paged:
             d.update({
@@ -425,20 +438,21 @@ class ServeEngine:
         for b in buckets:
             if self.paged:
                 self._refresh_tables()
-                logits, rows_cache = self._prefill_paged(
+                greedy, rows_cache = self._prefill_paged(
                     self.params, jnp.asarray(b.tokens), jnp.asarray(b.lens))
                 blk, off = self._block_offsets(b)
                 self.cache = self._splice_paged(
                     self.cache, rows_cache, jnp.asarray(b.slot_idx),
                     jnp.asarray(blk), jnp.asarray(off))
             else:
-                logits, cache1 = self._prefill(self.params,
+                greedy, cache1 = self._prefill(self.params,
                                                jnp.asarray(b.tokens),
                                                jnp.asarray(b.lens))
                 self.cache = self._splice(self.cache, cache1,
                                           jnp.asarray(b.slot_idx))
             self.prefill_calls += 1
-            first = np.asarray(jnp.argmax(logits, axis=-1))
+            first, finite = jax.device_get(greedy)
+            self.nonfinite_rows += int((~finite[:len(b.rows)]).sum())
             for row, i in enumerate(b.rows):
                 req, slot = reqs[i], slots[i]
                 req.out.append(int(first[row]))
@@ -527,11 +541,12 @@ class ServeEngine:
             decode = self._decode_paged
         else:
             decode = self._decode
-        logits, self.cache = decode(
+        greedy, self.cache = decode(
             self.params, self.cache,
             jnp.asarray(self.last_tok[:, None]), jnp.asarray(active_mask))
         self.decode_steps += 1
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        nxt, finite = jax.device_get(greedy)
+        self.nonfinite_rows += int((~finite & active_mask).sum())
         produced = 0
         for s, req in enumerate(self.active):
             if req is None:

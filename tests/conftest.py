@@ -21,24 +21,3 @@ def tiny_ecg():
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
-
-
-@pytest.fixture
-def make_auto_mesh():
-    """jax.make_mesh with Auto axis types across jax versions.
-
-    ``jax.sharding.AxisType`` only exists in newer jax; Auto is the default
-    there too, so on older versions plain make_mesh is equivalent.
-    (A fixture rather than an importable helper: pytest injects it under
-    any --import-mode.)
-    """
-    import jax
-
-    def _make(shape, axis_names):
-        kwargs = {}
-        if hasattr(jax.sharding, "AxisType"):
-            kwargs["axis_types"] = \
-                (jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(shape, axis_names, **kwargs)
-
-    return _make

@@ -19,11 +19,12 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import axis_rules, default_rules
+from repro.launch.mesh import make_mesh
 from repro.models.moe import init_moe, moe_block
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = default_rules(multi_pod=False)
 
     cfg = ModelConfig(

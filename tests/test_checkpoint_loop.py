@@ -10,6 +10,7 @@ import pytest
 from repro.checkpoint import Checkpointer
 from repro.configs import reduced_config
 from repro.data.lm import LMDataConfig, data_iterator, make_batch
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build_model
 from repro.training.loop import LoopConfig, train_loop
 from repro.training.step import TrainState, make_train_step
@@ -101,11 +102,11 @@ def test_train_loop_survives_injected_failures(tmp_path):
                                       np.asarray(b, np.float32))
 
 
-def test_elastic_restore_with_resharding(tmp_path, make_auto_mesh):
+def test_elastic_restore_with_resharding(tmp_path):
     """Checkpoints are mesh-agnostic: restore with explicit shardings on the
     (single-device) 'new mesh' still works leaf-for-leaf."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = make_auto_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     ck = Checkpointer(str(tmp_path), keep=1)
     state = _state()
     ck.save(1, state)

@@ -11,6 +11,7 @@ from repro.distributed.sharding import (
     shardings_like,
     spec_for,
 )
+from repro.launch.mesh import make_mesh
 from repro.launch.roofline import analyze_hlo
 
 SCAN_HLO = """\
@@ -42,8 +43,8 @@ ENTRY %main.3_spmd (param.2: f32[64,256], param.3: f32[10,64,256]) -> f32[64,256
 """
 
 
-def test_spec_resolution_and_taken_axes(make_auto_mesh):
-    mesh = make_auto_mesh((1, 1), ("data", "model"))
+def test_spec_resolution_and_taken_axes():
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = default_rules(multi_pod=False)
     # heads -> model; second use of model in the same spec is dropped
     s = spec_for(("embed", "heads"), rules, mesh)
@@ -64,8 +65,8 @@ def test_logical_constraint_noop_without_rules():
     np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def test_shardings_like_tuple_leaves(make_auto_mesh):
-    mesh = make_auto_mesh((1, 1), ("data", "model"))
+def test_shardings_like_tuple_leaves():
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = default_rules()
     template = {"w": jax.ShapeDtypeStruct((8, 8), np.float32),
                 "inner": {"b": jax.ShapeDtypeStruct((8,), np.float32)}}
