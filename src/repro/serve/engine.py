@@ -45,9 +45,18 @@ incrementally), and the :attr:`in_flight` / :attr:`queue_depth` /
 :attr:`has_work` load metrics.  ``decode_steps`` doubles as the heartbeat
 counter: a replica with work whose ``decode_steps`` stops advancing is
 stalled.
+
+Phase spans: each host phase of a round (:data:`PHASES` — the tick, the
+admitting round, each prefill bucket's dispatch and its wait, the decode
+step, its wait and the emit loop after it) opens a
+``jax.profiler.TraceAnnotation`` named ``engine.<phase>``, so a profiler
+trace lays every device idle gap against what the host was doing, and adds
+its host seconds and count to ``stats()["phase_s"]`` /
+``stats()["phase_n"]``.  The profiler being on or off is the only switch.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -59,6 +68,11 @@ import numpy as np
 from repro.core.faults import FaultPlan
 from repro.serve.buckets import build_buckets
 from repro.serve.paged import BlockPool
+
+# host phases of one scheduling round, each a ``TraceAnnotation`` named
+# ``engine.<phase>`` and a pair of counters in ``stats()``
+PHASES = ("tick", "admit", "prefill", "prefill_wait", "decode",
+          "decode_wait", "emit")
 
 
 @dataclasses.dataclass
@@ -250,6 +264,11 @@ class ServeEngine:
         self.shed_blocks = 0        # paged OOM sheds (explicit, counted)
         self.nonfinite_rows = 0     # live rows whose logits held NaN/inf
         self.peak_concurrency = 0   # max sequences simultaneously in flight
+        self.prefill_tokens = 0     # real prompt tokens prefilled
+        self.prefill_padded_tokens = 0  # rows x length of every prefill
+        #   bucket dispatched, pad rows included
+        self.phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.phase_n: Dict[str, int] = dict.fromkeys(PHASES, 0)
 
     def submit(self, req: ServeRequest) -> bool:
         """Queue a request.  Returns ``False`` (and flags the request
@@ -324,14 +343,20 @@ class ServeEngine:
 
     def stats(self) -> Dict[str, Any]:
         """Counters for loadgen reports: throughput-side (decode steps,
-        prefill dispatches), concurrency (peak sequences in flight) and —
-        for the paged engine — block-pool residency."""
+        prefill dispatches and their real and padded tokens), concurrency
+        (peak sequences in flight), host seconds and count of each engine
+        phase (``phase_s``/``phase_n``, keyed by :data:`PHASES`) and — for
+        the paged engine — block-pool residency."""
         d: Dict[str, Any] = {
             "decode_steps": self.decode_steps,
             "prefill_calls": self.prefill_calls,
+            "prefill_tokens": self.prefill_tokens,
+            "prefill_padded_tokens": self.prefill_padded_tokens,
             "peak_concurrency": self.peak_concurrency,
             "shed_blocks": self.shed_blocks,
             "nonfinite_rows": self.nonfinite_rows,
+            "phase_s": dict(self.phase_s),
+            "phase_n": dict(self.phase_n),
         }
         if self.paged:
             d.update({
@@ -341,6 +366,21 @@ class ServeEngine:
                 "peak_blocks_used": self.pool.peak_used,
             })
         return d
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **args: int):
+        """One engine phase: a ``jax.profiler.TraceAnnotation`` named
+        ``engine.<name>`` (with ``args`` as its stats), which lands in the
+        profiler's host trace on the device planes' clock and costs next
+        to nothing while no profiler runs, and the phase's host seconds
+        and count added to ``phase_s``/``phase_n``."""
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(f"engine.{name}", **args):
+                yield
+        finally:
+            self.phase_s[name] += time.perf_counter() - t0
+            self.phase_n[name] += 1
 
     # ------------------------------------------------------------ block pool
     def _release_blocks(self, slot: int, req: ServeRequest) -> None:
@@ -432,35 +472,44 @@ class ServeEngine:
             reqs = self.waiting[:take]
             del self.waiting[:take]
             slots = free[:take]
-        buckets = build_buckets([r.prompt for r in reqs], slots,
-                                self.cfg.slots, pad_to=self.cfg.pad_to,
-                                max_batch=self.cfg.max_prefill_batch)
-        for b in buckets:
-            if self.paged:
-                self._refresh_tables()
-                greedy, rows_cache = self._prefill_paged(
-                    self.params, jnp.asarray(b.tokens), jnp.asarray(b.lens))
-                blk, off = self._block_offsets(b)
-                self.cache = self._splice_paged(
-                    self.cache, rows_cache, jnp.asarray(b.slot_idx),
-                    jnp.asarray(blk), jnp.asarray(off))
-            else:
-                greedy, cache1 = self._prefill(self.params,
-                                               jnp.asarray(b.tokens),
-                                               jnp.asarray(b.lens))
-                self.cache = self._splice(self.cache, cache1,
-                                          jnp.asarray(b.slot_idx))
-            self.prefill_calls += 1
-            first, finite = jax.device_get(greedy)
-            self.nonfinite_rows += int((~finite[:len(b.rows)]).sum())
-            for row, i in enumerate(b.rows):
-                req, slot = reqs[i], slots[i]
-                req.out.append(int(first[row]))
-                req.t_admit = now
-                req.t_first = now
-                self.active[slot] = req
-                self.last_tok[slot] = first[row]
-                self._maybe_finish(slot, now)
+        with self._phase("admit", admitted=len(reqs)):
+            buckets = build_buckets([r.prompt for r in reqs], slots,
+                                    self.cfg.slots, pad_to=self.cfg.pad_to,
+                                    max_batch=self.cfg.max_prefill_batch)
+            for b in buckets:
+                # the splice is dispatched here and not waited on: its
+                # device time lands in the next wait of this tick
+                with self._phase("prefill", rows=len(b.rows),
+                                 padded_len=b.tokens.shape[1]):
+                    if self.paged:
+                        self._refresh_tables()
+                        greedy, rows_cache = self._prefill_paged(
+                            self.params, jnp.asarray(b.tokens),
+                            jnp.asarray(b.lens))
+                        blk, off = self._block_offsets(b)
+                        self.cache = self._splice_paged(
+                            self.cache, rows_cache, jnp.asarray(b.slot_idx),
+                            jnp.asarray(blk), jnp.asarray(off))
+                    else:
+                        greedy, cache1 = self._prefill(
+                            self.params, jnp.asarray(b.tokens),
+                            jnp.asarray(b.lens))
+                        self.cache = self._splice(self.cache, cache1,
+                                                  jnp.asarray(b.slot_idx))
+                self.prefill_calls += 1
+                self.prefill_tokens += int(b.lens[:len(b.rows)].sum())
+                self.prefill_padded_tokens += int(b.tokens.size)
+                with self._phase("prefill_wait"):
+                    first, finite = jax.device_get(greedy)
+                self.nonfinite_rows += int((~finite[:len(b.rows)]).sum())
+                for row, i in enumerate(b.rows):
+                    req, slot = reqs[i], slots[i]
+                    req.out.append(int(first[row]))
+                    req.t_admit = now
+                    req.t_first = now
+                    self.active[slot] = req
+                    self.last_tok[slot] = first[row]
+                    self._maybe_finish(slot, now)
         return len(reqs)
 
     def _block_offsets(self, b):
@@ -529,33 +578,36 @@ class ServeEngine:
         active_mask = np.array([r is not None for r in self.active])
         if not active_mask.any():
             return 0
-        if self.paged:
-            # grow each active slot's table to cover this step's write
-            # position; pool exhaustion sheds explicitly (OOM), so the
-            # mask may shrink before the dispatch
-            self._grow_blocks(now)
-            active_mask = np.array([r is not None for r in self.active])
-            if not active_mask.any():
-                return 0
-            self._refresh_tables()
-            decode = self._decode_paged
-        else:
-            decode = self._decode
-        greedy, self.cache = decode(
-            self.params, self.cache,
-            jnp.asarray(self.last_tok[:, None]), jnp.asarray(active_mask))
-        self.decode_steps += 1
-        nxt, finite = jax.device_get(greedy)
-        self.nonfinite_rows += int((~finite & active_mask).sum())
-        produced = 0
-        for s, req in enumerate(self.active):
-            if req is None:
-                continue
-            req.out.append(int(nxt[s]))
-            self.last_tok[s] = nxt[s]
-            produced += 1
-            self._maybe_finish(s, now)
-        return produced
+        with self._phase("decode", rows=int(active_mask.sum())):
+            if self.paged:
+                # grow each active slot's table to cover this step's write
+                # position; pool exhaustion sheds explicitly (OOM), so the
+                # mask may shrink before the dispatch
+                self._grow_blocks(now)
+                active_mask = np.array([r is not None for r in self.active])
+                if not active_mask.any():
+                    return 0
+                self._refresh_tables()
+                decode = self._decode_paged
+            else:
+                decode = self._decode
+            greedy, self.cache = decode(
+                self.params, self.cache,
+                jnp.asarray(self.last_tok[:, None]), jnp.asarray(active_mask))
+            self.decode_steps += 1
+            with self._phase("decode_wait"):
+                nxt, finite = jax.device_get(greedy)
+            self.nonfinite_rows += int((~finite & active_mask).sum())
+            produced = 0
+            with self._phase("emit"):
+                for s, req in enumerate(self.active):
+                    if req is None:
+                        continue
+                    req.out.append(int(nxt[s]))
+                    self.last_tok[s] = nxt[s]
+                    produced += 1
+                    self._maybe_finish(s, now)
+            return produced
 
     # ----------------------------------------------------------------- tick
     def tick(self, now: float, *, realtime: bool = False
@@ -569,22 +621,26 @@ class ServeEngine:
         Returns ``{"produced", "admitted", "expired", "stall_s"}`` counts;
         ``stall_s`` is the injected ``serve.decode`` stall the caller must
         add to its virtual clock (``realtime=True`` sleeps it here)."""
-        expired = self._expire(now)
-        admitted = self._admit(now)
-        self.peak_concurrency = max(self.peak_concurrency,
-                                    sum(r is not None for r in self.active))
-        stall_s = 0.0
-        if self.faults is not None:
-            # injected decode stall: the engine owns no clock of its own, so
-            # the plan is consulted (check), never slept inside (fire) —
-            # the caller's virtual clock advances deterministically instead
-            spec = self.faults.check("serve.decode", step=self.decode_steps)
-            if spec is not None and spec.kind in ("hang", "stall"):
-                if realtime:
-                    time.sleep(spec.hang_s)
-                else:
-                    stall_s = spec.hang_s
-        produced = self.step(now + stall_s)
+        with self._phase("tick"):
+            expired = self._expire(now)
+            admitted = self._admit(now)
+            self.peak_concurrency = max(
+                self.peak_concurrency,
+                sum(r is not None for r in self.active))
+            stall_s = 0.0
+            if self.faults is not None:
+                # injected decode stall: the engine owns no clock of its
+                # own, so the plan is consulted (check), never slept inside
+                # (fire) — the caller's virtual clock advances
+                # deterministically instead
+                spec = self.faults.check("serve.decode",
+                                         step=self.decode_steps)
+                if spec is not None and spec.kind in ("hang", "stall"):
+                    if realtime:
+                        time.sleep(spec.hang_s)
+                    else:
+                        stall_s = spec.hang_s
+            produced = self.step(now + stall_s)
         return {"produced": produced, "admitted": admitted,
                 "expired": expired, "stall_s": stall_s}
 
