@@ -17,6 +17,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ModelConfig
 from repro.models.common import (
@@ -284,6 +285,37 @@ def attention_decode(
     return y, k_cache, v_cache
 
 
+def _slotted_qkv(p, x, lens, cfg: ModelConfig, use_rope: bool):
+    """q/k/v of one decode token per slot, rotated at each slot's own
+    position ``lens[b]``."""
+    b = x.shape[0]
+    q, k, v = _project_qkv(p, x, cfg)
+    if use_rope:
+        if cfg.mrope:
+            positions = jnp.broadcast_to(lens[None, :, None], (3, b, 1))
+        else:
+            positions = lens[:, None]
+        q, k = _rotate(q, k, positions, cfg)
+    return q, k, v
+
+
+def _slotted_attend(p, x, q, k_cache, v_cache, lens, cfg: ModelConfig,
+                    interpret: Optional[bool]):
+    """Each slot's query against its own valid prefix ``lens[b] + 1`` of
+    ``(B, S_max, KVH, hd)`` caches, then the output projection in the
+    dtype of the layer input ``x``."""
+    b = x.shape[0]
+    kv_len = lens + 1
+    if jax.default_backend() == "tpu":
+        from repro.kernels.decode_attention.ops import decode_attention
+        out = decode_attention(q[:, 0], k_cache, v_cache, kv_len,
+                               interpret=interpret)[:, None]
+    else:
+        out = chunked_attention(q, k_cache, v_cache, causal=False,
+                                chunk=cfg.attn_chunk, kv_len=kv_len)
+    return out.reshape(b, 1, -1) @ p["o"].astype(x.dtype)
+
+
 def attention_decode_slotted(
     p: Dict[str, jnp.ndarray],
     x: jnp.ndarray,                 # (B, 1, D)
@@ -308,29 +340,64 @@ def attention_decode_slotted(
 
     Returns (out, k_cache, v_cache).
     """
-    b = x.shape[0]
-    q, k, v = _project_qkv(p, x, cfg)
-    if use_rope:
-        if cfg.mrope:
-            positions = jnp.broadcast_to(lens[None, :, None], (3, b, 1))
-        else:
-            positions = lens[:, None]
-        q, k = _rotate(q, k, positions, cfg)
+    q, k, v = _slotted_qkv(p, x, lens, cfg, use_rope)
     pos_w = jnp.minimum(lens, k_cache.shape[1] - 1)
     upd = jax.vmap(lambda c, one, pw: jax.lax.dynamic_update_slice_in_dim(
         c, one, pw, axis=0))
     k_cache = upd(k_cache, k, pos_w)
     v_cache = upd(v_cache, v, pos_w)
-    kv_len = lens + 1
-    if jax.default_backend() == "tpu":
-        from repro.kernels.decode_attention.ops import decode_attention
-        out = decode_attention(q[:, 0], k_cache, v_cache, kv_len,
-                               interpret=interpret)[:, None]
-    else:
-        out = chunked_attention(q, k_cache, v_cache, causal=False,
-                                chunk=cfg.attn_chunk, kv_len=kv_len)
-    y = out.reshape(b, 1, -1) @ p["o"].astype(x.dtype)
+    y = _slotted_attend(p, x, q, k_cache, v_cache, lens, cfg, interpret)
     return y, k_cache, v_cache
+
+
+def tpu_cache_layout(head_dim: int) -> Layout:
+    """The layout a TPU gives a ``(L, B, S, KVH, hd)`` cache by default:
+    sequence-minor where ``hd`` is narrower than a 128-lane tile (so no
+    lane is padded), row-major otherwise."""
+    return Layout(major_to_minor=(0, 1, 3, 4, 2) if head_dim < 128
+                  else (0, 1, 2, 3, 4))
+
+
+def attention_decode_stacked(
+    p: Dict[str, jnp.ndarray],
+    x: jnp.ndarray,                 # (B, 1, D)
+    k_all: jnp.ndarray,             # (L, B, S_max, KVH, hd): every layer
+    v_all: jnp.ndarray,
+    layer,                          # scalar int32: this layer's index
+    lens: jnp.ndarray,              # (B,) int32: per-slot current lengths
+    cfg: ModelConfig,
+    use_rope: bool = True,
+    interpret: Optional[bool] = None,
+):
+    """:func:`attention_decode_slotted` against the layer-stacked cache.
+
+    Writes each slot's new KV row at ``[layer, b, min(lens[b], S_max-1)]``
+    with one row-sized dynamic-update-slice per slot, so a stack carried
+    through the layer loop is updated in place, then attends over layer
+    ``layer``'s plane, which holds what the slotted step's plane would:
+    the arithmetic is the same.  On TPU the stack is held in its default
+    layout (:func:`tpu_cache_layout`): left free, layout assignment moves
+    the whole carry into the kernel's layout, copying it in and out of the
+    layer loop, and a scatter copies it into a row-major layout and back.
+    Returns (out, k_all, v_all).
+    """
+    q, k, v = _slotted_qkv(p, x, lens, cfg, use_rope)
+    pos_w = jnp.minimum(lens, k_all.shape[2] - 1)
+    on_tpu = jax.default_backend() == "tpu"
+    layout = tpu_cache_layout(k_all.shape[-1])
+
+    def pin(c):
+        return with_layout_constraint(c, layout) if on_tpu else c
+
+    k_all, v_all = pin(k_all), pin(v_all)
+    for b in range(x.shape[0]):
+        at = (layer, b, pos_w[b], 0, 0)
+        k_all = jax.lax.dynamic_update_slice(k_all, k[b][None, None], at)
+        v_all = jax.lax.dynamic_update_slice(v_all, v[b][None, None], at)
+    k_all, v_all = pin(k_all), pin(v_all)
+    y = _slotted_attend(p, x, q, k_all[layer], v_all[layer], lens, cfg,
+                        interpret)
+    return y, k_all, v_all
 
 
 def attention_decode_paged(

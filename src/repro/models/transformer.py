@@ -25,7 +25,7 @@ from repro.models.attention import (
     attention_block,
     attention_decode,
     attention_decode_paged,
-    attention_decode_slotted,
+    attention_decode_stacked,
     attention_prefill,
     attention_specs,
     init_attention,
@@ -302,27 +302,35 @@ def lm_decode_step_slotted(
 ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """One decode step over every slot with independent lengths.
 
+    The stacked ``k``/``v`` caches ride the layer loop's carry, and each
+    layer writes only its new row per slot into them: the cache passed in
+    is consumed and updated in place when the caller donates it (the
+    serving engine does), instead of restacked into a fresh copy.
+
     Inactive slots still flow through the batch (their output logits are
     garbage and ignored by the engine) but their length does not advance,
     so the next admission's prefill overwrites a clean slot."""
     x = embed_tokens(params, tokens, cfg)
     lens = cache["lens"]
 
-    def scan_body(x_, layer):
-        lp, kc, vc = layer
+    def scan_body(carry, layer):
+        x_, k_all, v_all = carry
+        lp, li = layer
         h = apply_norm(cfg.norm, x_, lp["attn_norm"], cfg.norm_eps)
-        a, kc_new, vc_new = attention_decode_slotted(lp["attn"], h, kc, vc,
-                                                     lens, cfg)
+        a, k_all, v_all = attention_decode_stacked(lp["attn"], h, k_all,
+                                                   v_all, li, lens, cfg)
         h = x_ + a
         hn = apply_norm(cfg.norm, h, lp["mlp_norm"], cfg.norm_eps)
         if cfg.family == "moe":
             y, _ = moe_block(lp["moe"], hn, cfg)
         else:
             y = mlp_block(lp["mlp"], hn, cfg)
-        return h + y, (kc_new, vc_new)
+        return (h + y, k_all, v_all), None
 
-    x, (k_all, v_all) = jax.lax.scan(
-        scan_body, x, (params["layers"], cache["k"], cache["v"]))
+    layer_idx = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    (x, k_all, v_all), _ = jax.lax.scan(
+        scan_body, (x, cache["k"], cache["v"]),
+        (params["layers"], layer_idx))
     x = apply_norm(cfg.norm, x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, x, cfg)[:, 0]
     new_cache = {"k": k_all, "v": v_all,

@@ -42,8 +42,9 @@ def pad_batch(n: int, max_batch: int) -> int:
 @dataclasses.dataclass
 class PrefillBucket:
     """One prefill dispatch: ``tokens (B_pad, L)`` right-padded rows, true
-    ``lens``, and the destination slot per real row (pad rows get the
-    out-of-range slot index ``n_slots`` and are dropped by the splice)."""
+    ``lens``, and the destination slot per real row (pad rows come after
+    the real rows, get the out-of-range slot index ``n_slots`` and are
+    skipped by the splice)."""
 
     tokens: np.ndarray      # (B_pad, L) int32
     lens: np.ndarray        # (B_pad,) int32 (pad rows: 1)

@@ -127,7 +127,13 @@ class EngineConfig:
 
 
 class ServeEngine:
-    """Slot-cache continuous batching over a ModelBundle's slotted path."""
+    """Slot-cache continuous batching over a ModelBundle's slotted path.
+
+    The dense engine donates ``self.cache`` to each decode step and each
+    splice, which update it in place and hand back the buffer as the new
+    ``self.cache``: the arrays a caller read from ``engine.cache`` before a
+    ``tick``/``step`` are deleted by it, so callers must not keep a
+    reference to ``engine.cache`` across one."""
 
     def __init__(self, bundle, params, config: Optional[EngineConfig] = None,
                  faults: Optional[FaultPlan] = None):
@@ -194,13 +200,21 @@ class ServeEngine:
             return _greedy(logits), cache
 
         def _splice(cache, cache1, slot_idx):
-            # scatter each prefill row's cache into its slot; rows whose
-            # slot index is out of range (batch padding) are dropped
-            out = dict(cache)
-            for key, spec in self._specs.items():
-                ax = spec.index("batch")
-                idx = (slice(None),) * ax + (slot_idx,)
-                out[key] = cache[key].at[idx].set(cache1[key], mode="drop")
+            # copy each prefill row's cache into its slot with row-sized
+            # updates, so the donated cache is written in place; the pad
+            # rows of a bucket come last, with an out-of-range slot index,
+            # and are skipped
+            def put_row(r, out):
+                out = dict(out)
+                for key, spec in self._specs.items():
+                    ax = spec.index("batch")
+                    row = jax.lax.dynamic_slice_in_dim(cache1[key], r, 1,
+                                                       axis=ax)
+                    out[key] = jax.lax.dynamic_update_slice_in_dim(
+                        out[key], row, slot_idx[r], axis=ax)
+                return out
+            out = jax.lax.fori_loop(0, jnp.sum(slot_idx < cfg.slots),
+                                    put_row, dict(cache))
             out["lens"] = cache["lens"].at[slot_idx].set(
                 cache1["lens"], mode="drop")
             return out
@@ -233,8 +247,8 @@ class ServeEngine:
             return out
 
         self._prefill = jax.jit(_prefill)
-        self._decode = jax.jit(_decode)
-        self._splice = jax.jit(_splice)
+        self._decode = jax.jit(_decode, donate_argnums=(1,))
+        self._splice = jax.jit(_splice, donate_argnums=(0,))
         if cfg.paged:
             self._prefill_paged = jax.jit(_prefill_paged)
             self._decode_paged = jax.jit(_decode_paged)
@@ -267,6 +281,9 @@ class ServeEngine:
         self.prefill_tokens = 0     # real prompt tokens prefilled
         self.prefill_padded_tokens = 0  # rows x length of every prefill
         #   bucket dispatched, pad rows included
+        self.cache_updates = 0      # dense decode and splice dispatches
+        self.cache_inplace = 0      # of those, the ones that consumed the
+        #   donated cache (every leaf passed in deleted)
         self.phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
         self.phase_n: Dict[str, int] = dict.fromkeys(PHASES, 0)
 
@@ -345,8 +362,10 @@ class ServeEngine:
         """Counters for loadgen reports: throughput-side (decode steps,
         prefill dispatches and their real and padded tokens), concurrency
         (peak sequences in flight), host seconds and count of each engine
-        phase (``phase_s``/``phase_n``, keyed by :data:`PHASES`) and — for
-        the paged engine — block-pool residency."""
+        phase (``phase_s``/``phase_n``, keyed by :data:`PHASES`), dense
+        cache updates and how many of them were in place
+        (``cache_updates``/``cache_inplace``) and — for the paged engine —
+        block-pool residency."""
         d: Dict[str, Any] = {
             "decode_steps": self.decode_steps,
             "prefill_calls": self.prefill_calls,
@@ -355,6 +374,8 @@ class ServeEngine:
             "peak_concurrency": self.peak_concurrency,
             "shed_blocks": self.shed_blocks,
             "nonfinite_rows": self.nonfinite_rows,
+            "cache_updates": self.cache_updates,
+            "cache_inplace": self.cache_inplace,
             "phase_s": dict(self.phase_s),
             "phase_n": dict(self.phase_n),
         }
@@ -381,6 +402,14 @@ class ServeEngine:
         finally:
             self.phase_s[name] += time.perf_counter() - t0
             self.phase_n[name] += 1
+
+    def _count_update(self, old_cache) -> None:
+        """Count one dense cache update, and whether it consumed the
+        donated cache: JAX deletes a donated buffer only when XLA aliased
+        it to an output, so this counts real hand-overs."""
+        self.cache_updates += 1
+        self.cache_inplace += all(
+            a.is_deleted() for a in jax.tree_util.tree_leaves(old_cache))
 
     # ------------------------------------------------------------ block pool
     def _release_blocks(self, slot: int, req: ServeRequest) -> None:
@@ -494,8 +523,10 @@ class ServeEngine:
                         greedy, cache1 = self._prefill(
                             self.params, jnp.asarray(b.tokens),
                             jnp.asarray(b.lens))
-                        self.cache = self._splice(self.cache, cache1,
+                        old = self.cache
+                        self.cache = self._splice(old, cache1,
                                                   jnp.asarray(b.slot_idx))
+                        self._count_update(old)
                 self.prefill_calls += 1
                 self.prefill_tokens += int(b.lens[:len(b.rows)].sum())
                 self.prefill_padded_tokens += int(b.tokens.size)
@@ -591,9 +622,12 @@ class ServeEngine:
                 decode = self._decode_paged
             else:
                 decode = self._decode
+            old = self.cache
             greedy, self.cache = decode(
-                self.params, self.cache,
+                self.params, old,
                 jnp.asarray(self.last_tok[:, None]), jnp.asarray(active_mask))
+            if not self.paged:
+                self._count_update(old)
             self.decode_steps += 1
             with self._phase("decode_wait"):
                 nxt, finite = jax.device_get(greedy)

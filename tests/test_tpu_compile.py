@@ -6,9 +6,12 @@ refuse (tile alignment, VMEM limits) before any chip time is spent.  The
 shapes are the serving engine's at qwen2-0.5b widths in bf16: 8 slots, 14
 query heads over 2 KV heads, head_dim 64; dense over a 1024-token cache,
 paged over 16-token and 8-token blocks.  The kernels are compiled
-directly, because a whole decode step traced here would take the CPU
-branch of ``models/attention.py``.
+directly.  The serving engine's whole decode step and splice are compiled
+too, at the benchmark's 32 slots x 4096, with ``jax.default_backend``
+steered to the TPU so that ``models/attention.py`` takes its TPU branch.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -17,6 +20,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.decode_attention.kernel import (
     decode_attention_pallas, paged_decode_attention_pallas)
+from repro.models.attention import tpu_cache_layout
+from repro.models.registry import build_model
+from repro.serve import EngineConfig, ServeEngine
 
 CFG = get_config("qwen2-0.5b")
 SLOTS, CACHE_LEN = 8, 1024
@@ -77,3 +83,63 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, block_size):
             _spec((SLOTS,), jnp.int32, one_chip))
     compiled = jax.jit(paged_decode_attention_pallas).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_pinned_cache_layout_is_the_chips_default(one_chip, head_dim):
+    """The decode step holds its carried cache in the layout the chip
+    gives the cache's arrays, so entering the layer loop copies nothing."""
+    spec = _spec((24, 32, 4096, 2, head_dim), jnp.bfloat16, one_chip)
+    compiled = jax.jit(lambda a: a + 1).lower(spec).compile()
+    default = compiled.input_formats[0][0].layout
+    assert default.major_to_minor == \
+        tpu_cache_layout(head_dim).major_to_minor
+
+
+def _whole_cache_ops(hlo, shape):
+    """Instructions whose result has as many elements as the whole stacked
+    cache, by kind."""
+    n, kinds = 1, {}
+    for d in shape:
+        n *= d
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(", hlo):
+        e = 1
+        for d in m.group(1).split(","):
+            e *= int(d) if d else 1
+        if e == n:
+            kinds[m.group(2)] = kinds.get(m.group(2), 0) + 1
+    return kinds
+
+
+def test_engine_decode_and_splice_update_the_cache_in_place_on_v5e(
+        one_chip, monkeypatch):
+    """qwen2-0.5b's decode step and an 8-row splice into 32 slots x 4096:
+    the cache is aliased to the output, no whole-cache copy is left, and
+    the step's temporaries stay far below one cache (1.6 GB)."""
+    bundle = build_model(CFG)
+    shapes = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
+    eng = ServeEngine(bundle, shapes, EngineConfig(slots=2, cache_len=16))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _spec(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(shapes)
+    cache = on_chip(jax.eval_shape(lambda: bundle.make_slot_cache(32, 4096)))
+    rows = on_chip(jax.eval_shape(lambda: bundle.make_slot_cache(8, 4096)))
+    decode = eng._decode.lower(
+        params, cache, _spec((32, 1), jnp.int32, one_chip),
+        _spec((32,), jnp.bool_, one_chip)).compile()
+    splice = eng._splice.lower(
+        cache, rows, _spec((8,), jnp.int32, one_chip)).compile()
+    cache_bytes = 2 * cache["k"].size * 2
+    for compiled in (decode, splice):
+        hlo = compiled.as_text()
+        assert "input_output_alias" in hlo.split("\n", 1)[0]
+        kinds = _whole_cache_ops(hlo, cache["k"].shape)
+        assert "copy" not in kinds and "copy-start" not in kinds, kinds
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes
+        assert mem.temp_size_in_bytes < cache_bytes / 8
+    assert "tpu_custom_call" in decode.as_text()
