@@ -17,6 +17,7 @@ rep = 8, BK = 512, hd = 128 → ~0.8 MB.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def _softmax_update(q, k, v, start, kv_len, m, l, acc, *, seq_minor):
+    """Fold one tile of keys at positions ``start...`` into the online
+    softmax state ``(m, l, acc)`` of a ``(rep, hd)`` query (scaled, f32),
+    masking positions at or past ``kv_len``.  ``k``/``v`` are ``(n, hd)``
+    tiles, or ``(hd, n)`` where ``seq_minor``."""
+    n_dim = 1 if seq_minor else 0
+    s = jax.lax.dot_general(q, k.astype(jnp.float32),
+                            (((1,), (1 - n_dim,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(k_pos < kv_len, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1))
+    p = jnp.exp(s - m_new[:, None])
+    corr = jnp.exp(m - m_new)
+    l = l * corr + jnp.sum(p, axis=1)
+    acc = acc * corr[:, None] + jax.lax.dot_general(
+        p, v.astype(jnp.float32), (((1,), (n_dim,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_new, l, acc
+
+
+def _normalise(l, acc):
+    return acc / jnp.maximum(l, 1e-30)[..., None]
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -43,25 +69,13 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _compute():
         hd = q_ref.shape[-1]
         q = q_ref[0, 0].astype(jnp.float32) / (hd ** 0.5)   # (rep, hd)
-        k = k_ref[0, 0].astype(jnp.float32)                 # (BK, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos < kv_len, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        m_scr[...], l_scr[...], acc_scr[...] = _softmax_update(
+            q, k_ref[0, 0], v_ref[0, 0], j * bk, kv_len, m_scr[...],
+            l_scr[...], acc_scr[...], seq_minor=False)
 
     @pl.when(j == n_kv - 1)
     def _finish():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = _normalise(l_scr[...], acc_scr[...]).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(
@@ -109,6 +123,94 @@ def decode_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((b, kvh, rep, hd), q.dtype),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), qg, kt, vt)
+    return out.reshape(b, h, hd)
+
+
+def _stacked_kernel(layer_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+                    m_scr, l_scr, acc_scr, *, bk: int, n_kv: int):
+    # ``_kernel``'s online softmax over sequence-minor blocks: K and V
+    # arrive as (hd, BK), every KV head of the block in one grid step.  The
+    # layer index is consumed by the index maps.
+    del layer_ref
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    kv_len = len_ref[b]
+
+    @pl.when(j * bk < kv_len)   # skip fully-invalid cache blocks
+    def _compute():
+        hd = q_ref.shape[-1]
+        for g in range(q_ref.shape[1]):
+            q = q_ref[0, g].astype(jnp.float32) / (hd ** 0.5)   # (rep, hd)
+            m_scr[g], l_scr[g], acc_scr[g] = _softmax_update(
+                q, k_ref[0, 0, g], v_ref[0, 0, g], j * bk, kv_len, m_scr[g],
+                l_scr[g], acc_scr[g], seq_minor=True)
+
+    @pl.when(j == n_kv - 1)
+    def _finish():
+        o_ref[0] = _normalise(l_scr[...], acc_scr[...]).astype(o_ref.dtype)
+
+
+def decode_attention_stacked_pallas(
+    q: jnp.ndarray,        # (B, H, hd)
+    k_t: jnp.ndarray,      # (L, B, KVH, hd, S) every layer, sequence-minor
+    v_t: jnp.ndarray,
+    layer: jnp.ndarray,    # int32 scalar: the layer to attend over
+    kv_len: jnp.ndarray,   # (B,) int32
+    *,
+    block_k: int = 512,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """:func:`decode_attention_pallas` over one layer of the layer-stacked
+    cache, read where it lies.
+
+    ``k_t``/``v_t`` are the ``(L, B, S, KVH, hd)`` stack seen as
+    ``(L, B, KVH, hd, S)``: on a TPU that keeps the stack sequence-minor
+    (``hd`` below a 128-lane tile) the transpose is a bitcast.  The layer
+    and the lengths are scalar-prefetch operands, so each K/V block is
+    DMA'd straight out of the stack: no plane is sliced out or relaid."""
+    b, h, hd = q.shape
+    kvh, s = k_t.shape[2], k_t.shape[4]
+    rep = h // kvh
+    # the largest block that divides S and is at most ``block_k``: every
+    # S that the per-plane kernel takes (a multiple of 512) is taken here
+    bk = s if s <= block_k else math.gcd(s, block_k)
+    n_kv = s // bk
+
+    qg = q.reshape(b, kvh, rep, hd)
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
+    kv_spec = pl.BlockSpec((1, 1, kvh, hd, bk),
+                           lambda b_, j, li, lens: (li[0], b_, 0, 0, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, n_kv),
+        in_specs=[
+            pl.BlockSpec((1, kvh, rep, hd),
+                         lambda b_, j, li, lens: (b_, 0, 0, 0)),
+            kv_spec,
+            kv_spec,
+        ],
+        out_specs=pl.BlockSpec((1, kvh, rep, hd),
+                               lambda b_, j, li, lens: (b_, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((kvh, rep), jnp.float32),
+            pltpu.VMEM((kvh, rep), jnp.float32),
+            pltpu.VMEM((kvh, rep, hd), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_stacked_kernel, bk=bk, n_kv=n_kv),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rep, hd), q.dtype),
+        interpret=interpret,
+        name="decode_attention",
+    )(layer, kv_len.astype(jnp.int32), qg, k_t, v_t)
     return out.reshape(b, h, hd)
 
 

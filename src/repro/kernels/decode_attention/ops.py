@@ -8,7 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention.kernel import (
-    decode_attention_pallas, paged_decode_attention_pallas)
+    decode_attention_pallas, decode_attention_stacked_pallas,
+    paged_decode_attention_pallas)
 from repro.kernels.decode_attention.ref import (
     decode_attention_ref, gather_paged_kv, paged_decode_attention_ref)
 
@@ -41,6 +42,18 @@ def decode_attention(q, k, v, kv_len, *, impl: str = "pallas",
         return decode_attention_ref(q, k, v, kv_len)
     return decode_attention_pallas(q, k, v, kv_len, block_k=block_k,
                                    interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def decode_attention_stacked(q, k_t, v_t, layer, kv_len, *,
+                             interpret: Optional[bool] = None
+                             ) -> jnp.ndarray:
+    """Single-token GQA attention over layer ``layer`` of the stacked
+    cache, read in place.  q: (B,H,hd); k_t/v_t: (L,B,KVH,hd,S), the
+    ``(L,B,S,KVH,hd)`` stack transposed (a bitcast where the TPU keeps it
+    sequence-minor); layer: int32 scalar; kv_len: (B,)."""
+    return decode_attention_stacked_pallas(
+        q, k_t, v_t, layer, kv_len, interpret=resolve_interpret(interpret))
 
 
 def paged_decode_attention_chunked(q, k_pages, v_pages, tables, kv_len,
@@ -135,6 +148,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, kv_len, *,
 
 __all__ = [
     "decode_attention",
+    "decode_attention_stacked",
     "paged_decode_attention",
     "paged_decode_attention_chunked",
     "paged_decode_attention_ref",

@@ -304,7 +304,6 @@ def _slotted_attend(p, x, q, k_cache, v_cache, lens, cfg: ModelConfig,
     """Each slot's query against its own valid prefix ``lens[b] + 1`` of
     ``(B, S_max, KVH, hd)`` caches, then the output projection in the
     dtype of the layer input ``x``."""
-    b = x.shape[0]
     kv_len = lens + 1
     if jax.default_backend() == "tpu":
         from repro.kernels.decode_attention.ops import decode_attention
@@ -313,7 +312,12 @@ def _slotted_attend(p, x, q, k_cache, v_cache, lens, cfg: ModelConfig,
     else:
         out = chunked_attention(q, k_cache, v_cache, causal=False,
                                 chunk=cfg.attn_chunk, kv_len=kv_len)
-    return out.reshape(b, 1, -1) @ p["o"].astype(x.dtype)
+    return _out_proj(p, x, out)
+
+
+def _out_proj(p, x, out):
+    """The attention output projection, in the dtype of the layer input."""
+    return out.reshape(x.shape[0], 1, -1) @ p["o"].astype(x.dtype)
 
 
 def attention_decode_slotted(
@@ -358,6 +362,13 @@ def tpu_cache_layout(head_dim: int) -> Layout:
                   else (0, 1, 2, 3, 4))
 
 
+def _reads_stack_in_place(head_dim: int) -> bool:
+    """Whether the decode kernel reads the layer-stacked cache where it
+    lies: on a TPU that keeps the stack sequence-minor."""
+    return (jax.default_backend() == "tpu"
+            and tpu_cache_layout(head_dim).major_to_minor[-1] == 2)
+
+
 def attention_decode_stacked(
     p: Dict[str, jnp.ndarray],
     x: jnp.ndarray,                 # (B, 1, D)
@@ -379,6 +390,12 @@ def attention_decode_stacked(
     layout (:func:`tpu_cache_layout`): left free, layout assignment moves
     the whole carry into the kernel's layout, copying it in and out of the
     layer loop, and a scatter copies it into a row-major layout and back.
+
+    Where that layout is sequence-minor (``hd`` < 128), the stacked decode
+    kernel reads layer ``layer``'s blocks straight out of the stack, seen
+    as ``(L, B, KVH, hd, S)`` through a transpose that is a bitcast there.
+    Where it is row-major (``hd`` >= 128) the plane is sliced out and goes
+    to the per-plane kernel, whose block would be another body here.
     Returns (out, k_all, v_all).
     """
     q, k, v = _slotted_qkv(p, x, lens, cfg, use_rope)
@@ -395,6 +412,15 @@ def attention_decode_stacked(
         k_all = jax.lax.dynamic_update_slice(k_all, k[b][None, None], at)
         v_all = jax.lax.dynamic_update_slice(v_all, v[b][None, None], at)
     k_all, v_all = pin(k_all), pin(v_all)
+    if _reads_stack_in_place(k_all.shape[-1]):
+        from repro.kernels.decode_attention.ops import (
+            decode_attention_stacked)
+        seq_minor = (0, 1, 3, 4, 2)
+        out = decode_attention_stacked(
+            q[:, 0], jnp.transpose(k_all, seq_minor),
+            jnp.transpose(v_all, seq_minor), layer, lens + 1,
+            interpret=interpret)[:, None]
+        return _out_proj(p, x, out), k_all, v_all
     y = _slotted_attend(p, x, q, k_all[layer], v_all[layer], lens, cfg,
                         interpret)
     return y, k_all, v_all

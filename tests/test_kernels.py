@@ -1,4 +1,6 @@
 """Per-kernel allclose sweeps vs pure-jnp oracles (interpret=True on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -185,3 +187,40 @@ def test_decode_attention_empty_blocks_skipped():
     want = decode_attention_ref(q, k, v, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("H,S,block_k,lens", [
+    (4, 1024, 256, [1, 256, 256, 257, 1024]),       # rep 2
+    (14, 1024, None, [1, 511, 512, 513, 1024]),     # rep 7, own blocks
+    (14, 1024, 1024, [1, 512, 1023, 1024, 1024]),   # one block
+    (14, 1536, 1024, [1, 511, 512, 513, 1536]),     # S not a block multiple
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_stacked_matches_ref_on_every_layer(
+        H, S, block_k, lens, dtype):
+    """The stacked kernel reads layer ``layer`` of an (L, B, S, KVH, hd)
+    stack through its (L, B, KVH, hd, S) view, the layer a traced scalar;
+    lengths at 1, at and around a block boundary, and the whole cache.
+    Where ``block_k`` does not divide S the block is the largest divisor
+    of S below it (1536 over 1024: blocks of 512)."""
+    from repro.kernels.decode_attention.kernel import (
+        decode_attention_stacked_pallas)
+    from repro.kernels.decode_attention.ref import decode_attention_ref
+    L, B, KVH, hd = 3, 5, 2, 64
+    sizes = {"block_k": block_k} if block_k else {}
+    rng = np.random.default_rng(H + S)
+    q = jnp.asarray(rng.normal(size=(B, H, hd)), dtype)
+    k_all = jnp.asarray(rng.normal(size=(L, B, S, KVH, hd)), dtype)
+    v_all = jnp.asarray(rng.normal(size=(L, B, S, KVH, hd)), dtype)
+    lens = jnp.asarray(lens, jnp.int32)
+    seq_minor = (0, 1, 3, 4, 2)
+    k_t, v_t = jnp.transpose(k_all, seq_minor), jnp.transpose(v_all, seq_minor)
+    attend = jax.jit(functools.partial(decode_attention_stacked_pallas,
+                                       interpret=True, **sizes))
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for layer in range(L):
+        got = attend(q, k_t, v_t, jnp.int32(layer), lens)
+        want = decode_attention_ref(q, k_all[layer], v_all[layer], lens)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)

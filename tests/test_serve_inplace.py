@@ -17,6 +17,7 @@ import pytest
 
 from repro.configs import reduced_config
 from repro.models import transformer as T
+from repro.models import attention as A
 from repro.models.attention import attention_decode_slotted
 from repro.models.common import apply_norm
 from repro.models.mlp import mlp_block
@@ -195,4 +196,35 @@ def test_carried_cache_step_matches_the_restacking_step(arch):
         params, cache, tokens, active, cfg)
     for w, g in zip(jax.tree_util.tree_leaves(want),
                     jax.tree_util.tree_leaves(got)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_in_place_kernel_branch_matches_the_chunked_branch(monkeypatch,
+                                                          layer):
+    """``attention_decode_stacked`` through the stacked kernel (forced on,
+    in interpret mode) against the chunked attention it takes on the CPU:
+    the same output and the same stacks, from slots at mixed lengths (one
+    empty, one at a chunk's edge, one at the cache's last row)."""
+    cfg = reduced_config("qwen2-0.5b")
+    L, S = cfg.n_layers, 1024
+    hd, kvh = cfg.resolved_head_dim, cfg.n_kv_heads
+    rng = np.random.default_rng(layer)
+    p = A.init_attention(jax.random.PRNGKey(layer), cfg)
+    x = jnp.asarray(rng.standard_normal((SLOTS, 1, cfg.d_model)),
+                    jnp.float32)
+    k_all, v_all = (jnp.asarray(rng.standard_normal((L, SLOTS, S, kvh, hd)),
+                                jnp.float32) for _ in range(2))
+    lens = jnp.asarray([0, 511, S - 1, 700], jnp.int32)
+
+    def step():   # a fresh function, so the second call traces again
+        return jax.jit(lambda *a: A.attention_decode_stacked(*a, cfg))(
+            p, x, k_all, v_all, jnp.int32(layer), lens)
+
+    want = step()
+    monkeypatch.setattr(A, "_reads_stack_in_place", lambda head_dim: True)
+    got = step()
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-5)
+    for g, w in zip(got[1:], want[1:]):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
