@@ -79,9 +79,9 @@ def main():
     # Token-level LM serving has a paged KV cache (DESIGN.md §15):
     # serve_winner(..., paged=True) records the preference on the handle
     # (the classifier forward itself is cache-free) and
-    # launch/serve.py --engine --paged builds EngineConfig(paged=True) —
+    # launch/serve.py --paged builds EngineConfig(paged=True) —
     # admission on free pool *blocks* rather than worst-case dense
-    # slots, ~4x concurrency at equal memory on long-tail prompts.
+    # slots, so more requests fit at equal memory on long-tail prompts.
     # Prefer dense slots (the default) when prompts uniformly fill
     # cache_len or an admitted request must never be OOM-shed.
     print("\n== serving batched requests through the compiled forward ==")

@@ -1,7 +1,8 @@
-"""Example: batched LM serving with continuous slot reuse.
+"""Example: LM serving with continuous batching.
 
-Thin wrapper over repro.launch.serve with a reduced zoo config — the same
-BatchedServer the production driver uses.
+Thin wrapper over repro.launch.serve with a reduced zoo config: the same
+ServeEngine the serving driver runs (add ``--router`` or ``--paged`` for
+the replicated or paged-cache deployment).
 
 Run:  PYTHONPATH=src python examples/serve_lm.py --arch mamba2-780m
 """

@@ -20,8 +20,7 @@ def non_dominated_sort_reference(points: np.ndarray) -> List[np.ndarray]:
 
     O(N²) with a Python inner loop — kept as the executable reference that
     the vectorized :func:`non_dominated_sort` is property-tested against
-    (tests/test_pareto.py) and that benchmarks/nas_loop_bench.py times the
-    array-resident loop against.
+    (tests/test_pareto.py).
     """
     n = len(points)
     if n == 0:

@@ -6,7 +6,7 @@
   one submit/run/drain API: health-checked dispatch, failover, load
   shedding, hedged requests.
 * :mod:`repro.serve.loadgen` — open-loop Poisson / heavy-tail / burst /
-  long-tail-prompt workloads + latency stats.
+  long-tail-prompt workloads.
 * :mod:`repro.serve.paged` — :class:`~repro.serve.paged.BlockPool`
   block-granular KV-cache allocator behind ``EngineConfig(paged=True)``
   (DESIGN.md §15).
@@ -23,7 +23,6 @@ from repro.serve.engine import (
 )
 from repro.serve.loadgen import (
     gamma_workload,
-    latency_stats,
     longtail_workload,
     onoff_workload,
     poisson_workload,
@@ -53,7 +52,6 @@ __all__ = [
     "compile_winner",
     "gamma_workload",
     "greedy_reference",
-    "latency_stats",
     "longtail_workload",
     "onoff_workload",
     "poisson_workload",

@@ -95,16 +95,7 @@ class ServeRequest:
     # measured lifecycle (seconds from the run's t0)
     t_arrival: float = 0.0
     t_admit: float = 0.0
-    t_first: float = 0.0           # first token emitted (prefill argmax)
     t_done: float = 0.0
-
-    @property
-    def latency_s(self) -> float:
-        return self.t_done - self.t_arrival
-
-    @property
-    def ttft_s(self) -> float:
-        return self.t_first - self.t_arrival
 
 
 @dataclasses.dataclass
@@ -537,7 +528,6 @@ class ServeEngine:
                     req, slot = reqs[i], slots[i]
                     req.out.append(int(first[row]))
                     req.t_admit = now
-                    req.t_first = now
                     self.active[slot] = req
                     self.last_tok[slot] = first[row]
                     self._maybe_finish(slot, now)

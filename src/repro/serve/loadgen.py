@@ -1,4 +1,4 @@
-"""Open-loop load generation + latency accounting (DESIGN.md §12, §14).
+"""Open-loop load generation (DESIGN.md §12, §14).
 
 Open loop means arrivals follow their own clock (a Poisson process) and do
 NOT wait for the server — the honest way to measure a serving system,
@@ -13,7 +13,7 @@ Three arrival shapes, all seeded-deterministic:
 * :func:`gamma_workload` — gamma inter-arrivals with a chosen coefficient
   of variation: ``cv > 1`` produces heavy-tailed bursts (clumps of
   near-simultaneous arrivals separated by long gaps), the overload shape
-  the replica router's load shedding is benchmarked under;
+  for the replica router's load shedding;
 * :func:`onoff_workload` — on/off bursts: Poisson arrivals during on
   windows, silence during off windows — the diurnal/batch-upstream shape.
 * :func:`longtail_workload` — Poisson arrivals with *log-normal* prompt
@@ -23,7 +23,7 @@ Three arrival shapes, all seeded-deterministic:
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+from typing import List, Sequence
 
 import numpy as np
 
@@ -165,23 +165,3 @@ def longtail_workload(
             arrival_s=float(arrivals[i]),
         ))
     return reqs
-
-
-def latency_stats(finished: Sequence[ServeRequest],
-                  makespan_s: Optional[float] = None) -> Dict[str, float]:
-    """p50/p99 end-to-end latency + time-to-first-token and throughput."""
-    lat = np.array([r.latency_s for r in finished])
-    ttft = np.array([r.ttft_s for r in finished])
-    tokens = int(sum(len(r.out) for r in finished))
-    span = makespan_s if makespan_s is not None else (
-        max(r.t_done for r in finished) if len(finished) else 0.0)
-    return {
-        "requests": float(len(finished)),
-        "tokens": float(tokens),
-        "tok_per_s": tokens / span if span > 0 else 0.0,
-        "p50_latency_s": float(np.percentile(lat, 50)) if len(lat) else 0.0,
-        "p99_latency_s": float(np.percentile(lat, 99)) if len(lat) else 0.0,
-        "p50_ttft_s": float(np.percentile(ttft, 50)) if len(ttft) else 0.0,
-        "p99_ttft_s": float(np.percentile(ttft, 99)) if len(ttft) else 0.0,
-        "makespan_s": float(span),
-    }

@@ -430,7 +430,6 @@ class ReplicaRouter:
                 req.done = clone.done
                 req.expired = clone.expired
                 req.t_admit = clone.t_admit
-                req.t_first = clone.t_first
                 req.t_done = clone.t_done
                 req.oom = clone.oom
                 req.blocks_held = clone.blocks_held

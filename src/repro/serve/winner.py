@@ -51,7 +51,7 @@ class ServableWinner:
     _predict: Any = None           # jitted (B, L, 2) -> (B, n_classes)
     batches_served: int = 0
     paged: bool = False            # KV-cache preference recorded for the
-    #   token-serving deployment path (launch/serve.py --engine --paged);
+    #   token-serving deployment path (launch/serve.py --paged);
     #   the classifier forward itself has no KV cache
 
     def predict(self, x: np.ndarray) -> np.ndarray:
@@ -290,10 +290,10 @@ def serve_winner(
     failover, fail-streak quarantine — the resilient deployment default.
 
     ``paged=True`` records the paged KV-cache preference on the handle
-    for the token-serving deployment front-end (launch/serve.py builds
-    ``EngineConfig(paged=True)`` from it — DESIGN.md §15); the winner's
-    own classification forward is prefill-only and has no cache, so this
-    changes nothing about ``predict``.
+    for the token-serving deployment front-end (``launch/serve.py
+    --paged`` builds ``EngineConfig(paged=True)`` — DESIGN.md §15); the
+    winner's own classification forward is prefill-only and has no cache,
+    so this changes nothing about ``predict``.
 
     Raises ``LookupError`` when no candidate meets the goal's constraints
     (serve nothing rather than an infeasible model)."""

@@ -1,5 +1,6 @@
 """Evolutionary NAS loop with a mock trainer: selection + dormant-gene cache."""
 import numpy as np
+import pytest
 
 from repro.core.evolution import EvolutionarySearch, NASConfig
 from repro.core.objectives import cheap_matrix
@@ -153,6 +154,33 @@ def test_host_overlap_trajectory_is_bit_identical_to_off():
                   "front_size", "feasible", "best_primary"):
             assert ra[k] == rb[k] or (
                 np.isnan(ra[k]) and np.isnan(rb[k])), k
+
+
+class _CountingBackend:
+    """Counts ``evaluate_batch`` calls of the backend it wraps."""
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.calls = 0
+
+    def evaluate_batch(self, enc, *, space):
+        self.calls += 1
+        return self.inner.evaluate_batch(enc, space=space)
+
+
+@pytest.mark.parametrize("pipeline", ["off", "host_overlap"])
+def test_step_scores_cheap_objectives_once(pipeline):
+    """A generation step makes exactly one cheap-objective batch call, for
+    its children: the population's matrix is kept on the SoA state, never
+    recomputed."""
+    s = pipeline_search(pipeline)
+    state = s.init_state()
+    s.backend = _CountingBackend(s.backend)
+    for _ in range(3):
+        s.backend.calls = 0
+        state = s.step(state)
+        assert state.history[-1]["children"] > 0
+        assert s.backend.calls == 1
 
 
 def test_async_pipeline_completes_and_keeps_invariants():

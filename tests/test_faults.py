@@ -455,6 +455,42 @@ def test_router_chaos_parity_under_replica_loss_and_stall():
         assert r.out == refs[r.rid], r.rid
 
 
+def test_router_goodput_holds_under_replica_loss():
+    """Losing one of two replicas mid-run costs less than half the
+    goodput: the survivor keeps its slots full.  Goodput is completed
+    requests over the virtual makespan (one decode step is one virtual
+    second), so the ratio is the same on any machine."""
+    from repro.serve import (EngineConfig, ReplicaRouter, RouterConfig,
+                             poisson_workload)
+    cfg, bundle, params = _serve_setup()
+
+    def workload():
+        reqs = poisson_workload(24, vocab_size=cfg.vocab_size,
+                                rate_per_s=2.0, prompt_lens=(4, 8, 12, 16),
+                                out_lens=(4, 6, 8), seed=0)
+        for r in reqs:
+            r.deadline_s = 30.0
+        return reqs
+
+    def goodput(done):
+        ok = [r for r in done if r.done and not r.expired and not r.rejected]
+        assert len(ok) == len(done) == 24    # nothing shed, expired, lost
+        return len(ok) / max(r.t_done for r in ok)
+
+    rcfg = RouterConfig(replicas=2, engine=EngineConfig(
+        slots=4, cache_len=64, pad_to=8, max_prefill_batch=4))
+    fault_free = goodput(ReplicaRouter(bundle, params, rcfg).run(workload()))
+    plan = FaultPlan([FaultSpec(site="serve.replica", kind="device_loss",
+                                when=lambda c: c["replica"] == 0
+                                and c["tick"] == 5)])
+    router = ReplicaRouter(bundle, params, rcfg, faults=plan)
+    lossy = goodput(router.run(workload()))
+    assert plan.fired("serve.replica", kind="device_loss")
+    assert router.stats["quarantined"] == [0]
+    assert router.stats["failovers"] >= 1
+    assert lossy >= 0.6 * fault_free
+
+
 def test_router_dispatch_fault_redispatches():
     """A crash at the router.dispatch hand-off itself: the chosen replica
     is failed and restarted, the request is requeued, and everything still

@@ -22,7 +22,6 @@ from repro.serve import (
     build_buckets,
     gamma_workload,
     greedy_reference,
-    latency_stats,
     onoff_workload,
     poisson_workload,
 )
@@ -273,35 +272,6 @@ def test_engine_drain_completes_in_flight_only():
     assert all(s is None for s in engine.active)
 
 
-# ---------------------------------------------- wave baseline (regression)
-
-
-def test_batched_server_mixed_lengths_regression():
-    """The old BatchedServer shared one scalar ``cache['len']`` across
-    slots, so a wave mixing prompt lengths decoded from wrong positions.
-    The slotted rewrite must match the scalar reference bit for bit."""
-    from repro.launch.serve import BatchedServer
-    cfg, bundle, params = _bundle("qwen2-0.5b")
-    reqs = _requests(cfg, [(4, 8), (17, 8), (9, 8), (26, 8)])  # one wave
-    refs = _refs(bundle, params, reqs)
-    server = BatchedServer(bundle, params, slots=4, cache_len=CACHE_LEN)
-    done = server.run(reqs, log=lambda *_: None)
-    assert len(done) == 4
-    for r in done:
-        assert r.out == refs[r.rid], f"req {r.rid} diverged (stale cache)"
-
-
-def test_batched_server_hybrid_family():
-    from repro.launch.serve import BatchedServer
-    cfg, bundle, params = _bundle("zamba2-7b")
-    reqs = _requests(cfg, [(6, 4), (12, 4)])
-    refs = _refs(bundle, params, reqs)
-    server = BatchedServer(bundle, params, slots=2, cache_len=CACHE_LEN)
-    done = server.run(reqs, log=lambda *_: None)
-    for r in done:
-        assert r.out == refs[r.rid]
-
-
 # -------------------------------------------------------------- load gen
 
 
@@ -314,21 +284,6 @@ def test_poisson_workload_deterministic():
     assert arr == sorted(arr) and arr[-1] > 0
     burst = poisson_workload(4, vocab_size=64, rate_per_s=0.0)
     assert all(r.arrival_s == 0.0 for r in burst)
-
-
-def test_latency_stats():
-    reqs = []
-    for i in range(4):
-        r = ServeRequest(rid=i, prompt=np.zeros(4, np.int32), max_new=2,
-                         arrival_s=float(i))
-        r.t_arrival, r.t_first, r.t_done = float(i), i + 0.5, i + 1.0
-        r.out = [1, 2]
-        reqs.append(r)
-    s = latency_stats(reqs, makespan_s=4.0)
-    assert s["requests"] == 4 and s["tokens"] == 8
-    assert s["tok_per_s"] == pytest.approx(2.0)
-    assert s["p50_latency_s"] == pytest.approx(1.0)
-    assert s["p50_ttft_s"] == pytest.approx(0.5)
 
 
 # --------------------------------------------------------- winner serving
